@@ -14,7 +14,7 @@ import time
 from typing import List, Tuple
 
 from repro.sim.engine import ClusterConfig
-from repro.sim.replay import improvement, run_ab, warm_pool
+from repro.sim.replay import improvement, pool_workers, run_ab, warm_pool
 from repro.sim.workload import customer_replay_suite
 
 Row = Tuple[str, float, str]
@@ -24,11 +24,12 @@ def _workers() -> int:
     """Simulations are per-query independent; fan out across cores unless
     REPRO_BENCH_WORKERS pins it (0/1 = serial).  Capped: each worker is a
     full Python+jax process, and past ~8 the spawn cost outweighs the
-    parallelism for quick-mode suites."""
+    parallelism for quick-mode suites.  Off the CPU backend the suite
+    stays in this process, which holds the chip (`pool_workers`)."""
     env = os.environ.get("REPRO_BENCH_WORKERS")
     if env is not None:
-        return int(env)
-    return min(os.cpu_count() or 1, 8)
+        return pool_workers(int(env))
+    return pool_workers(min(os.cpu_count() or 1, 8))
 
 
 def run(quick: bool = False) -> List[Row]:

@@ -148,6 +148,9 @@ def main() -> None:
               file=sys.stderr)
         sys.exit(2)
 
+    from repro.runtime.compile_cache import configure_compile_cache
+
+    configure_compile_cache(_ROOT)
     print("name,us_per_call,derived")
     failures = 0
     record = {

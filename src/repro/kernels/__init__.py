@@ -6,5 +6,5 @@ topk_gating  — fused softmax + top-k routing
 ssd_scan     — Mamba-2 inter-chunk state recurrence
 
 Each kernel ships kernel.py (pl.pallas_call + BlockSpec), ops.py (jit'd
-wrapper with CPU interpret fallback), ref.py (pure-jnp oracle).
+wrapper: kernel or reference), ref.py (pure-jnp oracle).
 """

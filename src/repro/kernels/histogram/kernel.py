@@ -40,7 +40,7 @@ def load_histogram(
     *,
     num_dest: int,
     block_n: int = 1024,
-    interpret: bool = True,
+    interpret: bool = False,
 ) -> jax.Array:
     """Returns (num_dest,) float32 counts."""
     N = ids.shape[0]

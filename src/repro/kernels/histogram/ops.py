@@ -1,7 +1,5 @@
 """Jit'd public wrapper for the load-histogram kernel."""
 
-import jax
-
 from repro.kernels.histogram.kernel import load_histogram
 from repro.kernels.histogram.ref import load_histogram_ref
 
@@ -9,5 +7,4 @@ from repro.kernels.histogram.ref import load_histogram_ref
 def histogram(ids, num_dest: int, *, use_kernel: bool = True, **kw):
     if not use_kernel:
         return load_histogram_ref(ids, num_dest)
-    interpret = jax.default_backend() != "tpu"
-    return load_histogram(ids, num_dest=num_dest, interpret=interpret, **kw)
+    return load_histogram(ids, num_dest=num_dest, **kw)

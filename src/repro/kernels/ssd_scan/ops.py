@@ -1,7 +1,5 @@
 """Jit'd public wrapper for the SSD state scan."""
 
-import jax
-
 from repro.kernels.ssd_scan.kernel import ssd_state_scan
 from repro.kernels.ssd_scan.ref import ssd_state_scan_ref
 
@@ -9,5 +7,4 @@ from repro.kernels.ssd_scan.ref import ssd_state_scan_ref
 def state_scan(states, decay, *, use_kernel: bool = True, **kw):
     if not use_kernel:
         return ssd_state_scan_ref(states, decay)
-    interpret = jax.default_backend() != "tpu"
-    return ssd_state_scan(states, decay, interpret=interpret, **kw)
+    return ssd_state_scan(states, decay, **kw)

@@ -53,7 +53,7 @@ def topk_gating(
     *,
     k: int,
     block_t: int = 512,
-    interpret: bool = True,
+    interpret: bool = False,
 ):
     """Returns (weights (T,k) fp32 renormalized, indices (T,k) int32)."""
     T, E = logits.shape

@@ -1,7 +1,5 @@
 """Jit'd public wrapper for fused top-k gating."""
 
-import jax
-
 from repro.kernels.topk_gating.kernel import topk_gating
 from repro.kernels.topk_gating.ref import topk_gating_ref
 
@@ -9,5 +7,4 @@ from repro.kernels.topk_gating.ref import topk_gating_ref
 def gating(logits, k: int, *, use_kernel: bool = True, **kw):
     if not use_kernel:
         return topk_gating_ref(logits, k)
-    interpret = jax.default_backend() != "tpu"
-    return topk_gating(logits, k=k, interpret=interpret, **kw)
+    return topk_gating(logits, k=k, **kw)

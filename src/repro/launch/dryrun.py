@@ -1,6 +1,3 @@
-import os
-os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=512"
-
 """Multi-pod dry-run: lower + compile every (arch × shape × mesh) cell.
 
 For each cell this driver builds the production mesh, resolves shardings
@@ -18,6 +15,7 @@ import argparse
 import dataclasses
 import functools
 import json
+import os
 import sys
 import time
 import traceback
@@ -351,7 +349,16 @@ def run_cell(arch_id: str, shape_name: str, multi_pod: bool,
     return rec
 
 
+#: Host devices the production meshes are built from.
+_HOST_DEVICES_FLAG = "--xla_force_host_platform_device_count=512"
+
+
 def main() -> None:
+    # Before the first JAX call: the CPU backend reads XLA_FLAGS once, when
+    # it starts.  Appended, so flags the caller set are kept.
+    os.environ["XLA_FLAGS"] = " ".join(
+        f for f in (os.environ.get("XLA_FLAGS", ""), _HOST_DEVICES_FLAG) if f
+    )
     ap = argparse.ArgumentParser()
     ap.add_argument("--arch", type=str, default="")
     ap.add_argument("--shape", type=str, default="")

@@ -176,8 +176,9 @@ from __future__ import annotations
 
 import dataclasses
 import heapq
+import operator
 from collections import deque
-from functools import partial
+from functools import partial, reduce
 from typing import Callable, Deque, Dict, List, Optional, Tuple
 
 import jax
@@ -614,6 +615,17 @@ _KIND_NAMES = (
 #: Rows per service burst (completion-ack granularity).
 _SERVICE_CHUNK = 16
 
+
+def _seq_sum(costs: np.ndarray) -> float:
+    """Left-to-right float sum of a non-empty service chunk.
+
+    Every chunk total in the engine adds in row order: the closed forms'
+    ``np.cumsum`` and the multi-tenant ``np.bincount`` do so by
+    construction.  The built-in ``sum`` does not on Python >= 3.12, which
+    compensates float rounding (Neumaier), so it is not used here."""
+    return reduce(operator.add, costs.tolist())
+
+
 #: Sentinel: route_batch computes the destinations itself (no precomputed
 #: waterfill plan from a coalesced same-time arrival run).
 _RB_INLINE = object()
@@ -788,6 +800,11 @@ class MultiQuerySimulator:
         self.last_event_counts: Dict[str, int] = {}
         #: (time, old, new) resize log of the most recent autoscaled run.
         self.last_resizes: List[Tuple[float, int, int]] = []
+        #: Final link-state trees of the most recent `run`, one per tick
+        #: driver (batched groups, then per-tenant links).  A driver that
+        #: ticked holds the arrays its jitted tick returned, on the device
+        #: the tick ran on; one that never ticked holds host numpy arrays.
+        self.last_link_states: List[Dict[str, object]] = []
 
     def _none_fast_path_ok(self, tenants: List[TenantQuery]) -> bool:
         """True when the closed-form 'none' path may replace the loop."""
@@ -834,6 +851,7 @@ class MultiQuerySimulator:
             # times are a prefix sum — skip the event loop entirely.
             self.last_event_counts = {"none_closed_form_tenants": nq}
             self.last_fault_stats = {"enabled": False}
+            self.last_link_states = []
             if self.trace_placement:
                 self.last_placement = [
                     _producer_placement(t) for t in tenants
@@ -1209,11 +1227,11 @@ class MultiQuerySimulator:
             if ring.tail == ring.head:
                 return
             chunk, qids = ring.pop(_SERVICE_CHUNK)
-            # Sequential Python-float sum: bit-identical to the legacy
-            # engine's per-tuple accumulation, so the engines stay on the
-            # same event trajectory (tiny rounding differences amplify
+            # Sequential sum: bit-identical to the legacy engine's
+            # per-tuple accumulation, so the engines stay on the same
+            # event trajectory (tiny rounding differences amplify
             # chaotically through routing decisions).
-            total = sum(chunk.tolist())
+            total = _seq_sum(chunk)
             if qids is None:
                 counts = totals = None
             else:
@@ -2350,7 +2368,7 @@ class MultiQuerySimulator:
                 if ring.tail == ring.head:
                     return None
                 chunk, qids = ring.pop(_SERVICE_CHUNK)
-                tot = sum(chunk.tolist())
+                tot = _seq_sum(chunk)
                 if qids is None:
                     return (t_s + tot, inf, tot, len(chunk), None, None)
                 cnts = np.bincount(qids, minlength=nq)
@@ -2578,6 +2596,9 @@ class MultiQuerySimulator:
                 num_ticks=int(num_ticks[q]),
                 preempted_rows=int(preempted_rows[q]),
             ))
+        self.last_link_states = [g[0].state for g in groups] + [
+            link.state for link in links if link is not None
+        ]
         return results
 
 

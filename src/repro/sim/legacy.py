@@ -29,6 +29,15 @@ from repro.sim.engine import (
 _TICK, _ARRIVAL, _ENQUEUE, _DONE = 0, 1, 2, 3
 
 
+def _seq_total(rows: List[Tuple[float, float]]) -> float:
+    """Left-to-right sum of the rows' costs, the seed engine's order.
+    (The built-in ``sum`` compensates float rounding on Python >= 3.12.)"""
+    total = 0.0
+    for cst, _ in rows:
+        total += cst
+    return total
+
+
 class LegacySimulator:
     def __init__(
         self,
@@ -143,7 +152,7 @@ class LegacySimulator:
                 return
             rows = queue_rows[w][:service_chunk]
             queue_rows[w] = queue_rows[w][service_chunk:]
-            total = sum(cst for cst, _ in rows)
+            total = _seq_total(rows)
             worker_running[w] = True
             push(now + total, _DONE, w, rows)
 
@@ -283,7 +292,7 @@ class LegacySimulator:
             else:  # _DONE
                 w = who
                 rows = payload
-                total = sum(cst for cst, _ in rows)
+                total = _seq_total(rows)
                 busy_time[w] += total
                 rows_done[w] += len(rows)
                 sync_in_tick[w] += total

@@ -13,6 +13,7 @@ import warnings
 from concurrent.futures import ProcessPoolExecutor
 from typing import Callable, Dict, List, Optional, Sequence, Tuple
 
+import jax
 import numpy as np
 
 from repro.core.admission import (
@@ -160,17 +161,40 @@ def _discard_pool() -> None:
     _POOL_WORKERS = 0
 
 
+def pool_workers(workers: Optional[int]) -> int:
+    """Worker processes a suite may fan out to: ``workers`` on the CPU
+    backend, 0 (run in this process) otherwise.
+
+    An accelerator belongs to the one process that opened it.  A spawned
+    child that asks for it cannot have it and JAX quietly puts the child
+    on the CPU, so off the CPU every simulation stays in this process —
+    the one whose device the tick runs on."""
+    if not workers or workers <= 1:
+        return 0
+    backend = jax.default_backend()
+    if backend != "cpu":
+        warnings.warn(
+            f"default JAX backend is {backend!r}: simulations run in this "
+            "process, not in a worker pool (one process per chip)",
+            RuntimeWarning,
+        )
+        return 0
+    return workers
+
+
 def _map_queries(
     tasks: List[Tuple], workers: Optional[int]
 ) -> List[QueryResult]:
-    """Run simulation tasks, optionally on a 'spawn' process pool.
+    """Run simulation tasks, optionally on a 'spawn' process pool (CPU
+    backend only, see :func:`pool_workers`).
 
     Queries are independent, so results are deterministic regardless of
     ``workers``; any pool failure (restricted sandboxes) falls back to the
     serial path for THIS call and discards the broken pool, so the next
     call gets a fresh executor instead of inheriting the failure.
     """
-    if workers and workers > 1 and len(tasks) > 1:
+    workers = pool_workers(workers)
+    if workers and len(tasks) > 1:
         try:
             # Small chunks: per-query cost varies by >10x, so fine-grained
             # scheduling beats lower dispatch overhead.  Even-sized chunks
@@ -219,9 +243,11 @@ def warm_pool(workers: Optional[int]) -> list:
     it overlaps the caller's own setup.  Non-blocking; best-effort.  The
     warm-up futures are collected (and returned, mainly for tests): the
     first crash is surfaced as a RuntimeWarning instead of being
-    swallowed."""
+    swallowed.  Starts nothing off the CPU backend (see
+    :func:`pool_workers`)."""
     futures: list = []
-    if workers and workers > 1:
+    workers = pool_workers(workers)
+    if workers:
         try:
             pool = _get_pool(workers)
             for _ in range(workers):

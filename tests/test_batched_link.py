@@ -476,3 +476,25 @@ class TestPoolRecovery:
             warnings.simplefilter("error")
             futures = replay.warm_pool(workers=2)
         assert [f.result() for f in futures] == [True, True]
+
+    def test_accelerator_backend_starts_no_pool(self, monkeypatch):
+        """Off the CPU backend the parent holds the chip: no worker
+        process may be started, and the suite runs in-process."""
+
+        def _no_pool(workers):
+            raise AssertionError("a worker pool was requested")
+
+        monkeypatch.setattr(replay, "_get_pool", _no_pool)
+        monkeypatch.setattr(replay.jax, "default_backend", lambda: "tpu")
+        tasks = _tiny_tasks()
+        with pytest.warns(RuntimeWarning, match="one process per chip"):
+            assert replay.warm_pool(workers=4) == []
+        with pytest.warns(RuntimeWarning, match="one process per chip"):
+            results = replay._map_queries(tasks, workers=4)
+        serial = replay._map_queries(tasks, workers=None)
+        assert [r.latency for r in results] == [r.latency for r in serial]
+
+    @pytest.mark.parametrize("workers,want", [(None, 0), (0, 0), (1, 0),
+                                              (6, 6)])
+    def test_cpu_backend_keeps_pool_size(self, workers, want):
+        assert replay.pool_workers(workers) == want

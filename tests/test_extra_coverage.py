@@ -2,8 +2,11 @@
 migration behavior, data-pipeline permutation invariants, report
 rendering, launcher configs."""
 
+import os
+
 import jax.numpy as jnp
 import numpy as np
+import pytest
 
 from repro.core import AdaptiveLink, AdaptiveLinkConfig, DySkewConfig, Policy
 from repro.data.pipeline import DataConfig, DataPipeline
@@ -116,6 +119,17 @@ class TestLauncherConfigs:
                 # dropless in reduced mode (capacity covers worst case)
                 assert r.moe.capacity_factor >= r.moe.num_experts / r.moe.top_k
 
+    def test_dryrun_import_leaves_xla_flags(self, monkeypatch):
+        """Only dryrun's main() adds its host-device flag; importing the
+        module (as the test below does) must not touch XLA_FLAGS."""
+        import importlib
+
+        import repro.launch.dryrun as dryrun
+
+        monkeypatch.setenv("XLA_FLAGS", "--xla_dump_to=/dev/null")
+        importlib.reload(dryrun)
+        assert os.environ["XLA_FLAGS"] == "--xla_dump_to=/dev/null"
+
     def test_perf_flag_parsing(self):
         from repro.launch.dryrun import parse_flags
 
@@ -124,3 +138,41 @@ class TestLauncherConfigs:
         assert h7 and not h6
         flags, h7, h6 = parse_flags("")
         assert not flags.causal_skip and not h7
+
+
+class TestCompileCache:
+    """The entry points' cache rule: the environment's directory wins;
+    otherwise one fixed directory inside the checkout."""
+
+    @pytest.fixture(autouse=True)
+    def _restore_config(self):
+        import jax
+
+        keys = ("jax_compilation_cache_dir",
+                "jax_persistent_cache_min_compile_time_secs")
+        saved = {k: getattr(jax.config, k) for k in keys}
+        yield
+        for k, v in saved.items():
+            jax.config.update(k, v)
+
+    def test_environment_directory_is_left_to_jax(self, monkeypatch, tmp_path):
+        import jax
+
+        from repro.runtime.compile_cache import configure_compile_cache
+
+        before = jax.config.jax_compilation_cache_dir
+        monkeypatch.setenv("JAX_COMPILATION_CACHE_DIR", str(tmp_path / "env"))
+        assert configure_compile_cache(str(tmp_path)) == str(tmp_path / "env")
+        assert jax.config.jax_compilation_cache_dir == before
+        assert jax.config.jax_persistent_cache_min_compile_time_secs == 0.0
+
+    def test_default_is_fixed_directory_in_checkout(self, monkeypatch, tmp_path):
+        import jax
+
+        from repro.runtime.compile_cache import configure_compile_cache
+
+        monkeypatch.delenv("JAX_COMPILATION_CACHE_DIR", raising=False)
+        want = str(tmp_path / ".jax_cache")
+        assert configure_compile_cache(str(tmp_path)) == want
+        assert configure_compile_cache(str(tmp_path)) == want
+        assert jax.config.jax_compilation_cache_dir == want
