@@ -1,0 +1,37 @@
+"""A traffic file's per-query strategy rules, resolved as data.
+
+A traffic file gives one complete ``strategy`` (every field of the
+program's `StrategyConfig` and `DySkewConfig`, stated, not defaulted)
+and a list of ``rules``.  Each rule whose ``if`` matches the query's
+profile fields merges its ``set`` into the strategy, in order.  The
+result is a plain dict that `bench/program_io.py` turns into the
+program's config objects and `bench/reference.py` reads as it is.
+"""
+
+from __future__ import annotations
+
+import copy
+from typing import Dict
+
+from bench.gen import QueryProfile
+
+
+def _matches(cond: Dict, profile: QueryProfile) -> bool:
+    return all(getattr(profile, key) == want for key, want in cond.items())
+
+
+def _merge(base: Dict, patch: Dict) -> None:
+    for key, val in patch.items():
+        if isinstance(val, dict):
+            _merge(base[key], val)
+        else:
+            base[key] = val
+
+
+def resolve(traffic: Dict, profile: QueryProfile) -> Dict:
+    """The strategy one query runs under."""
+    out = copy.deepcopy(traffic["strategy"])
+    for rule in traffic.get("rules", []):
+        if _matches(rule.get("if", {}), profile):
+            _merge(out, rule["set"])
+    return out
