@@ -115,6 +115,9 @@ PINNED_MODULES = (
     "src/repro/runtime/fault_tolerance.py",
     "src/repro/sim/replay.py",
     "src/repro/sim/workload.py",
+    # The run's span recorder: telemetry beside the pinned results
+    # (tests/test_spans.py pins them bit-identical with it on).
+    "src/repro/sim/spans.py",
 )
 
 

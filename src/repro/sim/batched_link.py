@@ -42,6 +42,7 @@ import numpy as np
 
 from repro.core import state_machine
 from repro.core.types import DySkewConfig, link_state_init
+from repro.sim.spans import OFF, Spans
 
 
 def _next_pow2(x: int) -> int:
@@ -100,6 +101,9 @@ class BatchedLinkSim:
     rows masked inactive do not advance at all.
     """
 
+    #: Span recorder; the run that builds the driver hands it its own.
+    spans: Spans = OFF
+
     def __init__(self, cfg: DySkewConfig, n: int, num_tenants: int):
         self.cfg = cfg
         self.n = n
@@ -141,7 +145,8 @@ class BatchedLinkSim:
             self._pad(signal, bool),
             self._pad(active, bool),
         )
-        return np.asarray(distribute)[:t]
+        with self.spans.span("dyskew.tick.wait"):
+            return np.asarray(distribute)[:t]
 
     @property
     def states(self) -> np.ndarray:

@@ -177,6 +177,7 @@ from __future__ import annotations
 import dataclasses
 import heapq
 import operator
+import time
 from collections import deque
 from functools import partial, reduce
 from typing import Callable, Deque, Dict, List, Optional, Tuple
@@ -218,6 +219,8 @@ from repro.sim.faults import (
     FaultSchedule,
     default_sim_fault_config,
 )
+from repro.sim.spans import OFF as _SPANS_OFF
+from repro.sim.spans import Spans
 
 
 # --------------------------------------------------------------------- #
@@ -356,6 +359,9 @@ class AdaptiveLinkSim:
     """Host-side wrapper around the core state machines for all producer
     link instances of one query (they are siblings of each other)."""
 
+    #: Span recorder; the run that builds the driver hands it its own.
+    spans: Spans = _SPANS_OFF
+
     def __init__(self, cfg: DySkewConfig, n: int):
         self.cfg = cfg
         self.n = n
@@ -374,7 +380,8 @@ class AdaptiveLinkSim:
             bpr.astype(np.float32),
             signal.astype(bool),
         )
-        return np.asarray(distribute)
+        with self.spans.span("dyskew.tick.wait"):
+            return np.asarray(distribute)
 
     @property
     def states(self) -> np.ndarray:
@@ -796,7 +803,13 @@ class MultiQuerySimulator:
         self.last_placement: List[Optional[np.ndarray]] = []
         #: Per-kind event counters of the most recent `run` (heap events
         #: popped by kind, coalescing stats, drain stats).  Telemetry
-        #: only — reported by `benchmarks/bench_multi_tenant.py`.
+        #: only — read by the benchmark (`bench/program_io.py` and its
+        #: `bench/metrics/` readers), `chip_smoke.py` and `sim/replay.py`.
+        #: While a JAX profiler session records, the run also adds its
+        #: span totals (`repro.sim.spans`): ``span_ns:<span>`` and
+        #: ``span_n:<span>`` (inclusive wall ns and count of each
+        #: ``dyskew.*`` span) and ``event_ns:<kind>`` (loop wall ns from
+        #: one heap pop to the next, charged to the kind popped).
         self.last_event_counts: Dict[str, int] = {}
         #: (time, old, new) resize log of the most recent autoscaled run.
         self.last_resizes: List[Tuple[float, int, int]] = []
@@ -842,6 +855,19 @@ class MultiQuerySimulator:
         return _transfer_delay(self.cluster, src, dst, nbytes, nrows)
 
     def run(self, tenants: List[TenantQuery]) -> List[QueryResult]:
+        spans = Spans.for_run()
+        with spans.span("dyskew.run"):
+            try:
+                results = self._run(tenants, spans)
+            finally:
+                spans.phase(None)
+        if spans.on:
+            self.last_event_counts.update(spans.counts())
+        return results
+
+    def _run(self, tenants: List[TenantQuery],
+             spans: Spans) -> List[QueryResult]:
+        spans.phase("dyskew.setup")
         c = self.cluster
         n = c.num_workers
         nq = len(tenants)
@@ -849,6 +875,7 @@ class MultiQuerySimulator:
         if self._none_fast_path_ok(tenants):
             # No redistribution, disjoint producers: per-worker completion
             # times are a prefix sum — skip the event loop entirely.
+            spans.phase("dyskew.drain")
             self.last_event_counts = {"none_closed_form_tenants": nq}
             self.last_fault_stats = {"enabled": False}
             self.last_link_states = []
@@ -917,13 +944,13 @@ class MultiQuerySimulator:
                 origin = min(tenants[q].arrival for q in members)
                 for q in members:
                     group_of[q] = len(groups)
-                groups.append((
-                    BatchedLinkSim(cfg_g, n, len(members)),
-                    members, interval, origin,
-                ))
+                sim_g = BatchedLinkSim(cfg_g, n, len(members))
+                sim_g.spans = spans
+                groups.append((sim_g, members, interval, origin))
             else:
                 for q in members:
                     links[q] = AdaptiveLinkSim(strategies[q].dyskew, n)
+                    links[q].spans = spans
         # Per-group member state as contiguous arrays (the per-tick live
         # scan used to rebuild python lists per event — at T≳128 that
         # dominated the coalesced tick's host cost).
@@ -1835,9 +1862,23 @@ class MultiQuerySimulator:
             void_dead_rows(w)
             inject_recovered(now)
 
+        spans.phase("dyskew.loop")
+        span, spans_on = spans.span, spans.on
+        # Loop wall time per heap event kind (recorder on only): the time
+        # from one pop to the next is charged to the kind popped.  Like
+        # the spans it is telemetry: no virtual time or result reads it.
+        ev_ns = [0] * len(_KIND_NAMES)
+        ev_kind: Optional[int] = None
+        ev_t = 0
         now = 0.0
         while events:
             now, _, kind, qid, who, payload = heappop(events)
+            if spans_on:
+                # dyslint: disable=DY104 -- telemetry only, see above
+                t_ns = time.perf_counter_ns()
+                if ev_kind is not None:
+                    ev_ns[ev_kind] += t_ns - ev_t
+                ev_kind, ev_t = kind, t_ns
             if kind == _ENQUEUE:
                 enq_n += 1
                 w = who
@@ -1959,35 +2000,36 @@ class MultiQuerySimulator:
                 # the decommissioned-producer redirect (it credits
                 # kept-local rows to the inactive worker), so the batched
                 # plan would diverge from pop-order routing.
-                if not autoscale_on and not faults_on and events and \
-                        events[0][0] == now and (
-                    events[0][2] in (_ARRIVAL, _ADMITTED)
-                ):
-                    # A maximal run of same-instant arrivals: route them
-                    # through the batched waterfill path.
-                    run_ev = [(kind, qid, who, payload)]
-                    if kind == _ARRIVAL:
-                        arrival_n += 1
-                    else:
-                        admitted_n += 1
-                    while events and events[0][0] == now and events[0][2] in (
-                        _ARRIVAL, _ADMITTED
+                with span("dyskew.route"):
+                    if not autoscale_on and not faults_on and events and \
+                            events[0][0] == now and (
+                        events[0][2] in (_ARRIVAL, _ADMITTED)
                     ):
-                        _, _, k2, q2, w2, pl2 = heappop(events)
-                        run_ev.append((k2, q2, w2, pl2))
-                        if k2 == _ARRIVAL:
+                        # A maximal run of same-instant arrivals: route
+                        # them through the batched waterfill path.
+                        run_ev = [(kind, qid, who, payload)]
+                        if kind == _ARRIVAL:
                             arrival_n += 1
                         else:
                             admitted_n += 1
-                    arrival_runs += 1
-                    arrivals_in_runs += len(run_ev)
-                    route_arrival_run(now, run_ev)
-                else:
-                    if kind == _ARRIVAL:
-                        arrival_n += 1
+                        while events and events[0][0] == now and (
+                            events[0][2] in (_ARRIVAL, _ADMITTED)
+                        ):
+                            _, _, k2, q2, w2, pl2 = heappop(events)
+                            run_ev.append((k2, q2, w2, pl2))
+                            if k2 == _ARRIVAL:
+                                arrival_n += 1
+                            else:
+                                admitted_n += 1
+                        arrival_runs += 1
+                        arrivals_in_runs += len(run_ev)
+                        route_arrival_run(now, run_ev)
                     else:
-                        admitted_n += 1
-                    handle_arrival(kind, qid, who, payload, now)
+                        if kind == _ARRIVAL:
+                            arrival_n += 1
+                        else:
+                            admitted_n += 1
+                        handle_arrival(kind, qid, who, payload, now)
                 if (
                     drain_on and total_remaining == 0
                     and preempt_pending == 0 and events
@@ -2213,62 +2255,12 @@ class MultiQuerySimulator:
                         now + fcfg.heartbeat_interval, _HBEAT, 0, 0, None
                     )
             elif kind == _TICK:
-                tick_n += 1
-                q = qid
-                num_ticks[q] += 1
-                rows_arr = np.asarray(rows_arr_in_tick[q])
-                batches_arr = np.asarray(batches_arr_in_tick[q])
-                density = np.where(
-                    batches_arr > 0,
-                    rows_arr / np.maximum(batches_arr, 1),
-                    0.0,
-                )
-                bpr = np.where(
-                    rows_arr > 0,
-                    np.asarray(bytes_arr_in_tick[q]) / np.maximum(rows_arr, 1),
-                    0.0,
-                )
-                policies[q].set_link_mask(links[q].tick(
-                    np.asarray(recv_in_tick[q]), np.asarray(sync_in_tick[q]),
-                    density, bpr, np.asarray(worker_running, bool),
-                ).tolist())
-                recv_in_tick[q] = [0.0] * n
-                sync_in_tick[q] = [0.0] * n
-                rows_arr_in_tick[q] = [0.0] * n
-                batches_arr_in_tick[q] = [0.0] * n
-                bytes_arr_in_tick[q] = [0.0] * n
-                if active_flag[q]:
-                    push(now + strategies[q].tick_interval, _TICK, q, 0, None)
-            else:  # _GTICK — ONE coalesced tick drives a whole group
-                gtick_n += 1
-                g = qid
-                sim_g, members, interval, _ = groups[g]
-                # A member participates while it has arrived, has not
-                # already ticked at this instant (join tick colliding with
-                # a grid point), and is active — plus exactly one
-                # post-drain tick, mirroring the per-tenant cadence where
-                # the already-scheduled tick still fires after drain.
-                gact = grp_active[g]
-                if payload is None:
-                    elig = (
-                        (grp_arrival[g] <= now)
-                        & (grp_last_tick[g] != now)
-                        & (gact | ~grp_final[g])
-                    )
-                else:
-                    q = payload
-                    i = member_slot[q][1]
-                    elig = np.zeros(len(members), bool)
-                    if grp_last_tick[g][i] != now and (
-                        gact[i] or not grp_final[g][i]
-                    ):
-                        elig[i] = True
-                if elig.any():
-                    acc = group_acc[g]
-                    rows_arr = acc["rows"]
-                    batches_arr = acc["batches"]
-                    # Same elementwise formulas as the per-tenant tick,
-                    # lifted to (T, n) — bit-identical per row.
+                with span("dyskew.tick"):
+                    tick_n += 1
+                    q = qid
+                    num_ticks[q] += 1
+                    rows_arr = np.asarray(rows_arr_in_tick[q])
+                    batches_arr = np.asarray(batches_arr_in_tick[q])
                     density = np.where(
                         batches_arr > 0,
                         rows_arr / np.maximum(batches_arr, 1),
@@ -2276,33 +2268,98 @@ class MultiQuerySimulator:
                     )
                     bpr = np.where(
                         rows_arr > 0,
-                        acc["bytes"] / np.maximum(rows_arr, 1),
+                        np.asarray(bytes_arr_in_tick[q])
+                        / np.maximum(rows_arr, 1),
                         0.0,
                     )
-                    dist = sim_g.tick(
-                        acc["recv"], acc["sync"], density, bpr,
-                        np.asarray(worker_running, bool),
-                        elig,
-                    )
-                    idxs = np.flatnonzero(elig)
-                    num_ticks[grp_members_arr[g][idxs]] += 1
-                    grp_last_tick[g][idxs] = now
-                    # One bulk tolist (C loop) instead of a python-level
-                    # conversion per live member.
-                    dist_rows = dist.tolist()
-                    for i in idxs:
-                        policies[members[int(i)]].set_link_mask(
-                            dist_rows[int(i)]
+                    policies[q].set_link_mask(links[q].tick(
+                        np.asarray(recv_in_tick[q]),
+                        np.asarray(sync_in_tick[q]),
+                        density, bpr, np.asarray(worker_running, bool),
+                    ).tolist())
+                    recv_in_tick[q] = [0.0] * n
+                    sync_in_tick[q] = [0.0] * n
+                    rows_arr_in_tick[q] = [0.0] * n
+                    batches_arr_in_tick[q] = [0.0] * n
+                    bytes_arr_in_tick[q] = [0.0] * n
+                    if active_flag[q]:
+                        push(now + strategies[q].tick_interval, _TICK, q, 0,
+                             None)
+            else:  # _GTICK — ONE coalesced tick drives a whole group
+                with span("dyskew.tick"):
+                    gtick_n += 1
+                    g = qid
+                    sim_g, members, interval, _ = groups[g]
+                    # A member participates while it has arrived, has not
+                    # already ticked at this instant (join tick colliding
+                    # with a grid point), and is active — plus exactly one
+                    # post-drain tick, mirroring the per-tenant cadence
+                    # where the already-scheduled tick still fires after
+                    # drain.
+                    gact = grp_active[g]
+                    if payload is None:
+                        elig = (
+                            (grp_arrival[g] <= now)
+                            & (grp_last_tick[g] != now)
+                            & (gact | ~grp_final[g])
                         )
-                    # Fancy-index reset writes through to the same rows
-                    # the per-tenant accumulator aliases view.
-                    for key in ("recv", "sync", "rows", "batches", "bytes"):
-                        acc[key][idxs] = 0.0
-                    grp_final[g][idxs[~gact[idxs]]] = True
-                if payload is None and gact.any():
-                    push(now + interval, _GTICK, g, 0, None)
+                    else:
+                        q = payload
+                        i = member_slot[q][1]
+                        elig = np.zeros(len(members), bool)
+                        if grp_last_tick[g][i] != now and (
+                            gact[i] or not grp_final[g][i]
+                        ):
+                            elig[i] = True
+                    if elig.any():
+                        acc = group_acc[g]
+                        rows_arr = acc["rows"]
+                        batches_arr = acc["batches"]
+                        # Same elementwise formulas as the per-tenant
+                        # tick, lifted to (T, n) — bit-identical per row.
+                        density = np.where(
+                            batches_arr > 0,
+                            rows_arr / np.maximum(batches_arr, 1),
+                            0.0,
+                        )
+                        bpr = np.where(
+                            rows_arr > 0,
+                            acc["bytes"] / np.maximum(rows_arr, 1),
+                            0.0,
+                        )
+                        dist = sim_g.tick(
+                            acc["recv"], acc["sync"], density, bpr,
+                            np.asarray(worker_running, bool),
+                            elig,
+                        )
+                        idxs = np.flatnonzero(elig)
+                        num_ticks[grp_members_arr[g][idxs]] += 1
+                        grp_last_tick[g][idxs] = now
+                        # One bulk tolist (C loop) instead of a
+                        # python-level conversion per live member.
+                        dist_rows = dist.tolist()
+                        for i in idxs:
+                            policies[members[int(i)]].set_link_mask(
+                                dist_rows[int(i)]
+                            )
+                        # Fancy-index reset writes through to the same rows
+                        # the per-tenant accumulator aliases view.
+                        for key in ("recv", "sync", "rows", "batches",
+                                    "bytes"):
+                            acc[key][idxs] = 0.0
+                        grp_final[g][idxs[~gact[idxs]]] = True
+                    if payload is None and gact.any():
+                        push(now + interval, _GTICK, g, 0, None)
+
+        if ev_kind is not None:
+            # dyslint: disable=DY104 -- telemetry only, as in the loop
+            ev_ns[ev_kind] += time.perf_counter_ns() - ev_t
+            spans.event_ns = {
+                _KIND_NAMES[k]: v for k, v in enumerate(ev_ns) if v
+            }
 
         if drained:
+            spans.phase("dyskew.drain")
             # ---- Closed-form drain -------------------------------------
             # Every arrival has been routed (total_remaining == 0, which
             # also implies no parked fair-share work), so the events left
@@ -2524,6 +2581,7 @@ class MultiQuerySimulator:
                     drained_ticks += cnt + 1
                     gfin[i] = True
 
+        spans.phase("dyskew.finish")
         if as_policy is not None:
             self.last_resizes = list(as_policy.resizes)
         self.last_event_counts = {
