@@ -55,8 +55,10 @@ Engine invariants (the bars `tests/test_sim_equivalence.py` enforces):
     push), and a parallel int32 ``qbuf`` lane records each row's owning
     tenant whenever more than one tenant shares the cluster.  Batch
     routing groups rows per destination with ONE stable sort
-    (`_group_by_dest`), and event payloads are numpy segments, never
-    per-row Python tuples.
+    (`_group_by_dest`) and prices every group of a batch as arrays
+    (bytes, uplink finish times, arrivals); only the _ENQUEUE pushes
+    loop, in ascending destination order.  Event payloads are numpy
+    segments, never per-row Python tuples.
   * Bit-exactness bar.  The seed list-of-tuples engine is preserved in
     `repro.sim.legacy`, and the unified loop must reproduce its
     `QueryResult` to rtol=1e-9 for single-tenant runs (and for
@@ -65,8 +67,10 @@ Engine invariants (the bars `tests/test_sim_equivalence.py` enforces):
     through routing decisions — so the loop keeps the legacy engine's
     float operations in the legacy order: service-burst totals are
     sequential sums (``np.bincount`` weight accumulation, which adds in
-    index order), per-destination byte totals use numpy's pairwise
-    ``.sum()`` on the same element order the legacy masks produced, and
+    index order), per-destination byte totals equal numpy's pairwise
+    ``.sum()`` on the same element order the legacy masks produced
+    (`_group_bytes`), the uplink's clock advances by a left-to-right
+    ``cumsum``, and
     the EMA update is ``(1-a)*est + a*(total/rows)``.  Do not "simplify"
     these expressions.
   * Determinism.  Given the same tenants the engine is bit-reproducible:
@@ -522,6 +526,42 @@ def _group_by_dest(
     return sd, starts, ends, costs[order], sizes[order]
 
 
+#: Group length from which numpy's float ``.sum()`` stops adding left to
+#: right (numpy's pairwise summation unrolls 8 accumulators).
+_PAIRWISE_FROM = 8
+_COLUMNS = np.arange(_PAIRWISE_FROM - 1)
+
+
+def _group_bytes(
+    x: np.ndarray, starts: np.ndarray, ends: np.ndarray
+) -> Tuple[np.ndarray, int]:
+    """``[float(x[lo:hi].sum()) for lo, hi in zip(starts, ends)]`` as one
+    float64 array, bit for bit, and the number of groups summed one by
+    one.
+
+    numpy sums a float array of fewer than 8 elements left to right from
+    0.0, and pairwise (8 accumulators) from 8 up.  The short groups are
+    therefore summed here a column at a time for every group at once:
+    ``cumsum`` along a row adds left to right, a padded 0.0 leaves a sum
+    unchanged, and the closing ``+= 0.0`` is numpy's start from 0.0 (it
+    turns -0.0 into 0.0).  Groups of 8 or more rows keep numpy's own
+    ``.sum()``.  Groups are non-empty.
+    """
+    lens = ends - starts
+    longest = int(lens.max())
+    cols = _COLUMNS[:longest]
+    vals = x.take(starts[:, None] + cols, mode="clip")
+    vals[cols >= lens[:, None]] = 0.0
+    out = np.cumsum(vals, axis=1)[:, -1]
+    out += 0.0
+    if longest < _PAIRWISE_FROM:
+        return out, 0
+    long_groups = np.flatnonzero(lens >= _PAIRWISE_FROM).tolist()
+    for g in long_groups:
+        out[g] = x[starts[g]:ends[g]].sum()
+    return out, len(long_groups)
+
+
 def _producer_placement(tenant: "TenantQuery") -> Optional[np.ndarray]:
     """Placement of a 'none'-strategy tenant in closed form: every
     lineage-tagged row stays on its producing worker (the exact property
@@ -890,6 +930,7 @@ class MultiQuerySimulator:
         # a list index at this event grain).  Vector math converts the
         # lists once per tick / per routed batch instead.
         node = [w // c.interpreters_per_node for w in range(n)]
+        node_np = np.asarray(node, np.int64)
         net_bw, net_lat = c.network_bandwidth, c.network_latency
         ipc_bw, ipc_lat = c.ipc_bandwidth, c.ipc_latency
         ser = c.per_row_serialize
@@ -1063,6 +1104,7 @@ class MultiQuerySimulator:
         resize_n = 0
         arrival_runs = arrivals_in_runs = enq_coalesced = 0
         wf_calls = wf_rows = 0
+        route_groups = route_groups_summed = 0
         drained_events = drained_chunks = drained_ticks = 0
 
         planner: Optional[FairShareAdmission] = None
@@ -1359,6 +1401,7 @@ class MultiQuerySimulator:
             applied).  ``emit`` redirects the _ENQUEUE pushes into the
             run's coalescing buffer instead of the heap.
             """
+            nonlocal route_groups, route_groups_summed
             out_q = outstanding[q]
             if dests_pre is not _RB_INLINE:
                 dests = dests_pre
@@ -1409,42 +1452,52 @@ class MultiQuerySimulator:
             sd, starts, ends, costs_s, sizes_s = _group_by_dest(
                 dests, b.costs, b.sizes
             )
-            # Per-group pairwise .sum() matches the legacy masked sums
-            # bit-for-bit (same elements, same order, same algorithm).
+            # Every group at once, each float op in the order (and with
+            # the operands) of routing the groups one at a time:
+            # `_group_bytes` equals each group's `.sum()` bit for bit.
+            d = sd[starts]
+            nrows = ends - starts
+            nbytes, n_summed = _group_bytes(sizes_s, starts, ends)
+            route_groups += len(starts)
+            route_groups_summed += n_summed
             src_node = node[p]
-            for j in range(len(starts)):
-                lo, hi = starts[j], ends[j]
-                d = int(sd[lo])
-                nrows = hi - lo
-                nbytes = float(sizes_s[lo:hi].sum())
-                if node[d] != src_node:
-                    rows_redist[q] += nrows
-                    bytes_moved[q] += nbytes
-                    if contention:
-                        # Serialize on the source node's uplink.
-                        nf = nic_free_at[src_node]
-                        start = now if now > nf else nf
-                        occupy = nbytes / net_bw
-                        if faults_on and nic_factor[src_node] != 1.0:
-                            # Degraded uplink: occupancy stretches.
-                            occupy = occupy * nic_factor[src_node]
-                        nic_free_at[src_node] = start + occupy
-                        arrive = start + occupy + net_lat + nrows * ser
-                    else:
-                        bw_t = nbytes / net_bw
-                        if faults_on and nic_factor[src_node] != 1.0:
-                            bw_t = bw_t * nic_factor[src_node]
-                        arrive = now + net_lat + bw_t + nrows * ser
-                elif d == p:
-                    arrive = now + nrows * ser
+            serial = nrows * ser
+            arrive = now + serial                       # own worker
+            moved = d != p
+            rows_redist[q] += int(nrows[moved].sum())
+            remote = node_np[d] != src_node
+            same_node = moved & ~remote
+            if same_node.any():
+                arrive[same_node] = (now + ipc_lat) \
+                    + nbytes[same_node] / ipc_bw + serial[same_node]
+            if remote.any():
+                nb = nbytes[remote]
+                bytes_moved[q] = reduce(operator.add, nb.tolist(),
+                                        bytes_moved[q])
+                occupy = nb / net_bw
+                if faults_on and nic_factor[src_node] != 1.0:
+                    # Degraded uplink: occupancy stretches.
+                    occupy = occupy * nic_factor[src_node]
+                if contention:
+                    # The batch's remote groups serialize on the source
+                    # node's uplink, in destination order: cumsum adds
+                    # left to right, as the uplink's clock advances.
+                    nf = nic_free_at[src_node]
+                    start = now if now > nf else nf
+                    free = np.cumsum(np.concatenate(([start], occupy)))[1:]
+                    nic_free_at[src_node] = float(free[-1])
+                    arrive[remote] = (free + net_lat) + serial[remote]
                 else:
-                    rows_redist[q] += nrows
-                    arrive = now + ipc_lat + nbytes / ipc_bw + nrows * ser
+                    arrive[remote] = ((now + net_lat) + occupy) \
+                        + serial[remote]
+            for t, dd, k, lo, hi in zip(arrive.tolist(), d.tolist(),
+                                        nrows.tolist(), starts.tolist(),
+                                        ends.tolist()):
                 if emit is None:
-                    push(arrive, _ENQUEUE, q, d, costs_s[lo:hi])
+                    push(t, _ENQUEUE, q, dd, costs_s[lo:hi])
                 else:
-                    emit(arrive, q, d, costs_s[lo:hi])
-                out_q[d] += nrows
+                    emit(t, q, dd, costs_s[lo:hi])
+                out_q[dd] += k
 
         def try_admit(q: int, rows: int, nbytes: float, bpr: float,
                       now: float) -> bool:
@@ -2612,6 +2665,8 @@ class MultiQuerySimulator:
             "enqueues_coalesced": enq_coalesced,
             "waterfill_batched_calls": wf_calls,
             "waterfill_batched_rows": wf_rows,
+            "route_groups": route_groups,
+            "route_groups_summed": route_groups_summed,
             "drain_entered": int(drained),
             "drained_heap_events": drained_events,
             "drained_chunks": drained_chunks,
