@@ -9,8 +9,15 @@ query is a list of per-producer streams, each a list of
 the program's own input objects; `bench/reference.py` reads them as
 they are.
 
-On top of the copies sits what a cell draws from its ``--seed``
-(:func:`query_pool`): the seed orders a fixed set of query profiles and
+A configuration names its fixed query population in one of two forms
+(:func:`suite`): ``{"suite", "num_queries", "seed"}``, a generator of
+:data:`SUITES` with its arguments, or ``{"population": "<name>"}``, the
+profiles of ``bench/populations/<name>.json`` in file order
+(:func:`load_population`).  A population file states every field of
+every profile, the policy by name, so a deployment is added as data.
+
+On top of that sits what a cell draws from its ``--seed``
+(:func:`query_pool`): the seed orders the population's profiles and
 draws each row's cost, size and producer; the row count of every query
 comes from the configuration.
 """
@@ -18,12 +25,17 @@ comes from the configuration.
 from __future__ import annotations
 
 import dataclasses
+import json
+import os
 from typing import Dict, List, Sequence, Tuple
 
 import numpy as np
 
 #: Policy ids, as `repro.core.types.Policy` numbers them.
 POLICY_IDS = {"NEVER": 0, "LATE": 1, "EARLY": 2, "EAGER_SNOWPARK": 3}
+
+POPULATIONS = os.path.join(os.path.dirname(os.path.abspath(__file__)),
+                           "populations")
 
 Stream = List[Tuple[np.ndarray, np.ndarray]]
 
@@ -144,6 +156,53 @@ def customer_replay_suite(num_queries: int = 150, seed: int = 7) -> List[QueryPr
 
 SUITES = {"customer_replay": customer_replay_suite}
 
+_SUITE_KEYS = {"suite", "num_queries", "seed"}
+_POPULATION_KEYS = {"population"}
+
+
+def _profile(i: int, row: Dict) -> QueryProfile:
+    """One population file profile, every field stated and typed."""
+    where = f"profile {i} ({row.get('name', '?')!r})"
+    fields = {f.name: f.type for f in dataclasses.fields(QueryProfile)}
+    missing = sorted(fields.keys() - row.keys())
+    if missing:
+        raise ValueError(f"{where}: field {missing[0]!r} is missing")
+    unknown = sorted(row.keys() - fields.keys())
+    if unknown:
+        raise ValueError(f"{where}: unknown field {unknown[0]!r}")
+    out = {}
+    for key, kind in fields.items():
+        val = row[key]
+        if key == "policy":
+            if not isinstance(val, str) or val not in POLICY_IDS:
+                raise ValueError(f"{where}: field 'policy': unknown policy "
+                                 f"{val!r}, not one of {sorted(POLICY_IDS)}")
+            val = POLICY_IDS[val]
+        elif kind == "float" and type(val) is int:
+            val = float(val)
+        if type(val).__name__ != kind:
+            raise ValueError(f"{where}: field {key!r} must be of type "
+                             f"{kind}, not {val!r}")
+        out[key] = val
+    for key in ("n_rows", "batch_rows"):
+        if out[key] <= 0:
+            raise ValueError(f"{where}: field {key!r} must be positive, "
+                             f"not {out[key]!r}")
+    return QueryProfile(**out)
+
+
+def load_population(name: str, directory: str = POPULATIONS) -> List[QueryProfile]:
+    """The profiles of ``<directory>/<name>.json``, in file order.
+
+    Raises ValueError, naming the profile and the field, for a missing
+    or unknown field, a value of the wrong type, an unknown policy name
+    and a non-positive ``n_rows`` or ``batch_rows``."""
+    with open(os.path.join(directory, name + ".json")) as f:
+        doc = json.load(f)
+    if not doc["profiles"]:
+        raise ValueError(f"population {name!r} holds no profiles")
+    return [_profile(i, row) for i, row in enumerate(doc["profiles"])]
+
 
 def scan_arrival_gap(
     prof: QueryProfile, num_workers: int, feed_factor: float = 2.0
@@ -180,9 +239,20 @@ class Query:
         return float(sum(float(c.sum()) for s in self.streams for c, _ in s))
 
 
-def suite(queries: Dict) -> List[QueryProfile]:
-    """The configuration's fixed query population."""
-    return SUITES[queries["suite"]](queries["num_queries"], queries["seed"])
+def suite(queries: Dict, populations: str = POPULATIONS) -> List[QueryProfile]:
+    """The configuration's fixed query population: a population file
+    found by name in ``populations``, or a suite generator's output.
+
+    Raises ValueError unless ``queries`` holds exactly one of the two
+    forms."""
+    keys = set(queries)
+    if keys == _POPULATION_KEYS:
+        return load_population(queries["population"], populations)
+    if keys == _SUITE_KEYS:
+        return SUITES[queries["suite"]](queries["num_queries"], queries["seed"])
+    raise ValueError(
+        f"queries must be {sorted(_POPULATION_KEYS)} or "
+        f"{sorted(_SUITE_KEYS)}, not {sorted(keys)}")
 
 
 def draw(

@@ -4,7 +4,10 @@ the check that decides ``correct``.
 Everything that belongs to a configuration, a traffic mix or a
 per-layer metric is data found by name: the configuration file named in
 ``BENCHMARK.json``, ``bench/traffic/<traffic>.json`` and
-``bench/metrics/<metric>.py``.
+``bench/metrics/<metric>.py``.  A configuration's ``queries`` names its
+query population either as a file, ``{"population": "<name>"}`` for
+``bench/populations/<name>.json``, or as a suite generator of
+`bench/gen.py` with its arguments (`gen.suite`).
 
 A *job* is one call of the program's served entry,
 `MultiQuerySimulator.run` on one query (``"job": "query"``).  Set-up
@@ -41,7 +44,10 @@ CACHE_SUBDIR = ".jax_cache"
 
 
 def load_cell(name: str, root: str = ROOT) -> Dict:
-    """The cell's entry, configuration, traffic and metric lists."""
+    """The cell's entry, configuration, traffic and metric lists, and
+    the directory its population files are found in.  Exits non-zero
+    when the configuration's ``queries`` is not exactly one of the two
+    forms, or its population file is malformed."""
     with open(os.path.join(root, "BENCHMARK.json")) as f:
         bench = json.load(f)
     cells = {w["name"]: w for w in bench["workloads"]}
@@ -51,8 +57,14 @@ def load_cell(name: str, root: str = ROOT) -> Dict:
     entry = next(c for c in bench["configs"] if c["name"] == cell["config"])
     with open(os.path.join(root, entry["file"])) as f:
         config = json.load(f)
-    with open(os.path.join(HERE, "traffic", cell["traffic"] + ".json")) as f:
+    with open(os.path.join(root, "bench", "traffic",
+                           cell["traffic"] + ".json")) as f:
         traffic = json.load(f)
+    populations = os.path.join(root, "bench", "populations")
+    try:
+        gen.suite(config["queries"], populations)
+    except ValueError as e:
+        raise SystemExit(f"{entry['file']}: {e}") from e
 
     def mine(metrics: List[Dict]) -> List[Dict]:
         return [m for m in metrics
@@ -62,6 +74,7 @@ def load_cell(name: str, root: str = ROOT) -> Dict:
         "chips": cell["chips"],
         "config": config,
         "traffic": traffic,
+        "populations": populations,
         "end_to_end": mine(bench["end_to_end"]),
         "per_layer": mine(bench["per_layer"]),
     }
@@ -156,7 +169,7 @@ def generate(cell: Dict, seed: int) -> Dict:
     wh = config["warehouse"]
     n = wh["num_nodes"] * wh["interpreters_per_node"]
     ff = traffic["feed_factor"]
-    profiles = gen.suite(config["queries"])
+    profiles = gen.suite(config["queries"], cell["populations"])
     pool = [[q] for p in range(int(traffic["passes"]))
             for q in gen.query_pool(profiles, n, ff, derived_seed(seed, 1, p))]
     warm, seen = [], set()

@@ -3,7 +3,9 @@
 A traffic file gives one complete ``strategy`` (every field of the
 program's `StrategyConfig` and `DySkewConfig`, stated, not defaulted)
 and a list of ``rules``.  Each rule whose ``if`` matches the query's
-profile fields merges its ``set`` into the strategy, in order.  The
+profile fields merges its ``set`` into the strategy, in order.  An
+``if`` may name the profile's declared ``policy`` by name
+(``{"policy": "LATE"}``) or by its id in `bench.gen.POLICY_IDS`.  The
 result is a plain dict that `bench/program_io.py` turns into the
 program's config objects and `bench/reference.py` reads as it is.
 """
@@ -13,11 +15,21 @@ from __future__ import annotations
 import copy
 from typing import Dict
 
-from bench.gen import QueryProfile
+from bench.gen import POLICY_IDS, QueryProfile
+
+
+def _want(key: str, want):
+    if key == "policy" and isinstance(want, str):
+        if want not in POLICY_IDS:
+            raise ValueError(f"rule names unknown policy {want!r}, not one "
+                             f"of {sorted(POLICY_IDS)}")
+        return POLICY_IDS[want]
+    return want
 
 
 def _matches(cond: Dict, profile: QueryProfile) -> bool:
-    return all(getattr(profile, key) == want for key, want in cond.items())
+    wants = {key: _want(key, want) for key, want in cond.items()}
+    return all(getattr(profile, key) == want for key, want in wants.items())
 
 
 def _merge(base: Dict, patch: Dict) -> None:
