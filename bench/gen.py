@@ -19,7 +19,10 @@ every profile, the policy by name, so a deployment is added as data.
 On top of that sits what a cell draws from its ``--seed``
 (:func:`query_pool`): the seed orders the population's profiles and
 draws each row's cost, size and producer; the row count of every query
-comes from the configuration.
+comes from the configuration.  A job of several concurrent queries also
+draws their arrival times in virtual seconds (:func:`job_arrivals`):
+`workload.arrival_times`'s Poisson or on/off process, snapped down onto
+the tick grid that `replay.open_loop_tenants(grid_align=...)` builds.
 """
 
 from __future__ import annotations
@@ -212,6 +215,77 @@ def scan_arrival_gap(
     ideal = prof.n_rows * prof.mean_row_cost / num_workers
     nbatches = max(prof.n_rows // min(prof.batch_rows, prof.n_rows), 1)
     return ideal / (feed_factor * nbatches)
+
+
+# --------------------------------------------------------------------- #
+# Arrivals of a job of concurrent queries
+# --------------------------------------------------------------------- #
+
+#: The fields of a traffic file's ``arrivals``, by process.
+ARRIVAL_FIELDS = {
+    "poisson": ("process", "rate"),
+    "burst": ("process", "rate", "burst_factor", "burst_fraction",
+              "mean_burst_s"),
+}
+
+#: The most tick intervals a job's arrivals may span, as the program's
+#: `engine._arrivals_on_grid` walks them.
+MAX_GRID_STEPS = 1 << 20
+
+
+def arrival_times(arrivals: Dict, num_arrivals: int, seed: int) -> np.ndarray:
+    """``num_arrivals`` open-loop arrival times in virtual seconds
+    (`workload.arrival_times`): a Poisson stream at ``rate``, or a
+    2-state on/off MMPP whose bursts, exponential with mean
+    ``mean_burst_s``, cover ``burst_fraction`` of the time at ``rate *
+    burst_factor``."""
+    rng = np.random.default_rng(seed)
+    rate = arrivals["rate"]
+    if arrivals["process"] == "poisson":
+        return np.cumsum(rng.exponential(1.0 / rate, num_arrivals))
+    if arrivals["process"] != "burst":
+        raise ValueError(f"unknown arrival process {arrivals['process']!r}")
+    f = min(max(arrivals["burst_fraction"], 1e-6), 1 - 1e-6)
+    mean_off_s = arrivals["mean_burst_s"] * (1.0 - f) / f
+    times: List[float] = []
+    t, on = 0.0, False
+    while len(times) < num_arrivals:
+        dur = rng.exponential(arrivals["mean_burst_s"] if on else mean_off_s)
+        r = rate * (arrivals["burst_factor"] if on else 1.0)
+        a = t + rng.exponential(1.0 / r)
+        while a < t + dur and len(times) < num_arrivals:
+            times.append(a)
+            a += rng.exponential(1.0 / r)
+        t += dur
+        on = not on
+    return np.asarray(times)
+
+
+def on_grid(times: np.ndarray, step: float) -> np.ndarray:
+    """Each time (>= 0) snapped down onto the chained float grid ``0,
+    0+step, (0+step)+step, ...``, built by repeated addition as
+    `replay.open_loop_tenants(grid_align=...)` builds it, so that every
+    value is one the program's tick chain reaches exactly from any
+    earlier one."""
+    kmax = int(np.floor(float(times.max()) / step)) + 1
+    if kmax > MAX_GRID_STEPS:
+        raise ValueError(f"arrivals span {kmax} ticks of {step!r} s, more "
+                         f"than {MAX_GRID_STEPS}")
+    chain = np.empty(kmax + 1)
+    t = 0.0
+    for k in range(kmax + 1):
+        chain[k] = t
+        t += step
+    idx = np.searchsorted(chain, times, side="right") - 1
+    return chain[np.clip(idx, 0, kmax)]
+
+
+def job_arrivals(arrivals: Dict, num_queries: int, step: float,
+                 seed: int) -> List[float]:
+    """One job's arrival times: the first at 0, the later gaps those of
+    the process drawn from ``seed``, each time on the grid of ``step``."""
+    times = arrival_times(arrivals, num_queries, seed)
+    return [float(t) for t in on_grid(times - times[0], step)]
 
 
 # --------------------------------------------------------------------- #

@@ -10,12 +10,16 @@ query population either as a file, ``{"population": "<name>"}`` for
 `bench/gen.py` with its arguments (`gen.suite`).
 
 A *job* is one call of the program's served entry,
-`MultiQuerySimulator.run` on one query (``"job": "query"``).  Set-up
-draws the traffic file's number of ``passes`` over the configuration's
-queries, each pass in its own order and with its own rows, and builds
-the program's input objects for every job of them; the window runs
-these jobs back to back, so no job hands the program an input it has
-seen before.  The window ends when the job running at ``--seconds``
+`MultiQuerySimulator.run`.  The traffic file's ``job`` says what it
+holds: one query arriving at 0 (``"query"``), or ``concurrency``
+queries sharing the warehouse, the first arriving at 0 and the others
+at times drawn from ``arrivals`` and snapped onto the strategies' tick
+grid (``"concurrent"``, `check_traffic`).  Set-up draws the traffic
+file's number of ``passes`` over the configuration's queries, each pass
+in its own order and with its own rows, cuts them in that order into
+jobs, and builds the program's input objects for every job; the window
+runs these jobs back to back, so no job hands the program an input it
+has seen before.  The window ends when the job running at ``--seconds``
 completes, and every rate is taken over all the rows of all the
 window's jobs and the whole time from the window's start to the end of
 its last job.
@@ -23,6 +27,7 @@ its last job.
 
 from __future__ import annotations
 
+import dataclasses
 import importlib.util
 import json
 import os
@@ -43,11 +48,66 @@ CACHE_HIT_EVENT = "/jax/compilation_cache/cache_hits"
 CACHE_SUBDIR = ".jax_cache"
 
 
+def _positive(where: str, val, kind=(int, float)) -> None:
+    if isinstance(val, bool) or not isinstance(val, kind) or not val > 0:
+        raise ValueError(f"field {where!r} must be a positive "
+                         f"{'int' if kind is int else 'number'}, not {val!r}")
+
+
+def check_traffic(traffic: Dict, profiles) -> None:
+    """Raise ValueError, naming the field, unless ``traffic`` is a
+    ``"query"`` traffic without the fields of a concurrent one, or a
+    ``"concurrent"`` one with a positive int ``concurrency`` and an
+    ``arrivals`` of exactly its process's fields (`gen.ARRIVAL_FIELDS`),
+    each a positive number (``burst_fraction`` below 1), under whose
+    strategies every profile ticks at one positive ``tick_interval``."""
+    kind = traffic.get("job")
+    if kind == "query":
+        extra = sorted({"concurrency", "arrivals"} & traffic.keys())
+        if extra:
+            raise ValueError(f"field {extra[0]!r} is for a concurrent job, "
+                             "not for 'query'")
+        return
+    if kind != "concurrent":
+        raise ValueError(f"field 'job': unknown job kind {kind!r}, not "
+                         "'query' or 'concurrent'")
+    for key in ("concurrency", "arrivals"):
+        if key not in traffic:
+            raise ValueError(f"field {key!r} is missing")
+    _positive("concurrency", traffic["concurrency"], int)
+    arr = traffic["arrivals"]
+    if not isinstance(arr, dict) or "process" not in arr:
+        raise ValueError("field 'arrivals.process' is missing")
+    fields = gen.ARRIVAL_FIELDS.get(arr["process"])
+    if fields is None:
+        raise ValueError(f"field 'arrivals.process': unknown process "
+                         f"{arr['process']!r}, not one of "
+                         f"{sorted(gen.ARRIVAL_FIELDS)}")
+    unknown = sorted(arr.keys() - set(fields))
+    if unknown:
+        raise ValueError(f"field 'arrivals.{unknown[0]}' is unknown to "
+                         f"process {arr['process']!r}")
+    for key in fields[1:]:
+        if key not in arr:
+            raise ValueError(f"field 'arrivals.{key}' is missing")
+        _positive(f"arrivals.{key}", arr[key])
+    if arr.get("burst_fraction", 0.0) >= 1.0:
+        raise ValueError("field 'arrivals.burst_fraction' must lie below 1, "
+                         f"not {arr['burst_fraction']!r}")
+    ticks = sorted({strategy.resolve(traffic, p)["tick_interval"]
+                    for p in profiles}, key=repr)
+    if len(ticks) != 1:
+        raise ValueError("field 'tick_interval': the strategies of a "
+                         f"concurrent job must share one, not {ticks}")
+    _positive("tick_interval", ticks[0])
+
+
 def load_cell(name: str, root: str = ROOT) -> Dict:
     """The cell's entry, configuration, traffic and metric lists, and
     the directory its population files are found in.  Exits non-zero
     when the configuration's ``queries`` is not exactly one of the two
-    forms, or its population file is malformed."""
+    forms, its population file is malformed, or the traffic file fails
+    `check_traffic`."""
     with open(os.path.join(root, "BENCHMARK.json")) as f:
         bench = json.load(f)
     cells = {w["name"]: w for w in bench["workloads"]}
@@ -57,14 +117,18 @@ def load_cell(name: str, root: str = ROOT) -> Dict:
     entry = next(c for c in bench["configs"] if c["name"] == cell["config"])
     with open(os.path.join(root, entry["file"])) as f:
         config = json.load(f)
-    with open(os.path.join(root, "bench", "traffic",
-                           cell["traffic"] + ".json")) as f:
+    traffic_file = os.path.join("bench", "traffic", cell["traffic"] + ".json")
+    with open(os.path.join(root, traffic_file)) as f:
         traffic = json.load(f)
     populations = os.path.join(root, "bench", "populations")
     try:
-        gen.suite(config["queries"], populations)
+        profiles = gen.suite(config["queries"], populations)
     except ValueError as e:
         raise SystemExit(f"{entry['file']}: {e}") from e
+    try:
+        check_traffic(traffic, profiles)
+    except ValueError as e:
+        raise SystemExit(f"{traffic_file}: {e}") from e
 
     def mine(metrics: List[Dict]) -> List[Dict]:
         return [m for m in metrics
@@ -160,24 +224,43 @@ def rate(units: float, window: Dict) -> float:
 def generate(cell: Dict, seed: int) -> Dict:
     """The cell's jobs, each a list of generated queries with their
     strategies: ``passes`` pools of every configured query, each pass
-    ordered and drawn from its own seed, and the warm-up's own queries
-    (one per distinct strategy, from a seed of their own).  Pure set-up;
-    the program sees none of it yet."""
+    ordered and drawn from its own seed, cut in that order into jobs;
+    and the warm-up's own jobs, from a seed of their own.  A ``"query"``
+    traffic warms up one query per distinct strategy; a ``"concurrent"``
+    one, per distinct strategy, a job of each size 1 to ``concurrency``
+    of the strategy's query with the fewest rows, all arriving at 0, so
+    that every tick group a window job can form is compiled.  Pure
+    set-up; the program sees none of it yet."""
     config, traffic = cell["config"], cell["traffic"]
-    if traffic["job"] != "query":
-        raise ValueError(f"unknown job kind {traffic['job']!r}")
     wh = config["warehouse"]
     n = wh["num_nodes"] * wh["interpreters_per_node"]
     ff = traffic["feed_factor"]
     profiles = gen.suite(config["queries"], cell["populations"])
-    pool = [[q] for p in range(int(traffic["passes"]))
-            for q in gen.query_pool(profiles, n, ff, derived_seed(seed, 1, p))]
-    warm, seen = [], set()
+    queries = [q for p in range(int(traffic["passes"]))
+               for q in gen.query_pool(profiles, n, ff, derived_seed(seed, 1, p))]
+    by_strategy: Dict[str, List[int]] = {}
     for i, prof in enumerate(profiles):
         key = json.dumps(strategy.resolve(traffic, prof), sort_keys=True)
-        if key not in seen:
-            seen.add(key)
-            warm.append([gen.draw(profiles, i, n, ff, derived_seed(seed, 5))])
+        by_strategy.setdefault(key, []).append(i)
+    if traffic["job"] == "query":
+        pool = [[q] for q in queries]
+        warm = [[gen.draw(profiles, idx[0], n, ff, derived_seed(seed, 5))]
+                for idx in by_strategy.values()]
+    else:
+        k = traffic["concurrency"]
+        step = strategy.resolve(traffic, profiles[0])["tick_interval"]
+        pool = []
+        for j, first in enumerate(range(0, len(queries), k)):
+            job = queries[first:first + k]
+            times = gen.job_arrivals(traffic["arrivals"], len(job), step,
+                                     derived_seed(seed, 6, j))
+            pool.append([dataclasses.replace(q, arrival=t)
+                         for q, t in zip(job, times)])
+        warm = []
+        for idx in by_strategy.values():
+            i = min(idx, key=lambda i: profiles[i].n_rows)
+            warm += [[gen.draw(profiles, i, n, ff, derived_seed(seed, 5, t, m))
+                      for m in range(t)] for t in range(1, k + 1)]
 
     def strategies(jobs):
         return [[strategy.resolve(traffic, q.profile) for q in job]
@@ -203,21 +286,38 @@ def _sum_counts(counts: List[Dict[str, int]]) -> Dict[str, int]:
     return out
 
 
+def sample_jobs(pool: List[List], ran: List[int], queries: int,
+                seed: int) -> set:
+    """The pool entries of the window's jobs ``ran`` that the check
+    compares: the longest job (the most rows in all its queries), then
+    whole jobs drawn from ``seed`` until at least ``queries`` queries are
+    in.  With one query a job that is the longest query and
+    ``queries - 1`` others."""
+    longest = max(ran, key=lambda e: sum(q.rows for q in pool[e]))
+    rest = [e for e in ran if e != longest]
+    k = min(len(rest), max(queries - 1, 0))
+    pick = np.random.default_rng([seed, 4]).choice(len(rest), k, replace=False)
+    out, held = {longest}, len(pool[longest])
+    for i in pick:
+        if held >= queries:
+            break
+        out.add(rest[int(i)])
+        held += len(pool[rest[int(i)]])
+    return out
+
+
 def check(cell: Dict, gen_out: Dict, jobs: List[Dict], seed: int) -> Dict:
     """Compare the window's answers with the reference.
 
-    A sample of the jobs the window ran is drawn from ``seed``, always
-    with the longest query in it, and compared whole.  Busy-time
-    conservation is checked on every window job.  A job that answers
-    for fewer or more queries than it was given fails every number."""
+    A sample of the jobs the window ran is drawn from ``seed``
+    (`sample_jobs`), always with the longest job in it, and compared
+    whole.  Busy-time conservation is checked on every window job.  A
+    job that answers for fewer or more queries than it was given fails
+    every number."""
     config, traffic = cell["config"], cell["traffic"]
     pool, strats = gen_out["pool"], gen_out["strategies"]
     ran = sorted({j["entry"] for j in jobs})
-    longest = max(ran, key=lambda e: pool[e][0].rows)
-    rest = [e for e in ran if e != longest]
-    k = min(len(rest), max(int(traffic["compare_queries"]) - 1, 0))
-    pick = np.random.default_rng([seed, 4]).choice(len(rest), k, replace=False)
-    sample = {longest, *(rest[int(i)] for i in pick)}
+    sample = sample_jobs(pool, ran, int(traffic["compare_queries"]), seed)
     gaps, cons = [], 0.0
     t0 = time.perf_counter()
     refs = {e: reference.run(config["warehouse"], pool[e], strats[e])
@@ -297,8 +397,11 @@ def run(cell: Dict, seed: int, seconds: float, trace: bool, devices,
     jax.monitoring.unregister_event_listener(counter.on_event)
     jobs = win["outs"]
     window_rows = sum(rows[j["entry"]] for j in jobs)
+    counts = _sum_counts([j["counts"] for j in jobs])
     log(f"window: {len(jobs)} jobs, {window_rows} rows in "
-        f"{win['end'] - win['start']!r} s, {compiles} compilations")
+        f"{win['end'] - win['start']!r} s, {compiles} compilations, "
+        f"{counts.get('tick', 0)} per-query and {counts.get('gtick', 0)} "
+        "group tick calls")
     if len(jobs) + int(traffic["traced_jobs"]) * trace > len(pool):
         log(f"the window ran past the {len(pool)} generated jobs and "
             "replayed some of them")

@@ -136,7 +136,7 @@ def test_every_cell_finds_its_files_by_name():
         bench = json.load(f)
     for w in bench["workloads"]:
         cell = harness.load_cell(w["name"])
-        assert cell["traffic"]["job"] == "query"
+        assert cell["traffic"]["job"] in ("query", "concurrent")
         assert set(cell["config"]["correct_limits"]) == set(harness.compare.NUMBERS)
         assert any(m["name"] == "setup_s" for m in cell["end_to_end"])
         for m in cell["per_layer"]:
