@@ -30,6 +30,11 @@ Key properties:
     see `engine._arrivals_on_grid` for the envelope check.
     `tests/test_batched_link.py` asserts state-for-state equality against
     T independent `AdaptiveLinkSim` instances across mixed cadences.
+  * One transfer per tick.  The six per-tick inputs reach the jitted
+    tick as one packed float32 host array, its only host argument, and
+    every driver of a (config, T, n) shape starts from that shape's
+    cached all-zero state on the device, so building a driver transfers
+    nothing (`tests/test_tick_transfers.py`).
 """
 
 from __future__ import annotations
@@ -49,46 +54,63 @@ def _next_pow2(x: int) -> int:
     return 1 << max(int(x) - 1, 0).bit_length() if x > 1 else 1
 
 
-def _stacked_host_link_state(
-    capacity: int, n: int, cfg: DySkewConfig
-) -> Dict[str, np.ndarray]:
-    """Host-numpy (T, ...) stack of `types.link_state_init` trees: one
-    row per tenant slot, same leaves and dtypes by construction (derived
-    from the canonical tree, so a new metric leaf cannot silently desync
-    the batched layout), no device round-trip.  Valid because every leaf
-    of the initial state is zero (LinkState.INIT == 0)."""
-    template = link_state_init(n, cfg)
+def _link_state_shapes(capacity: int, n: int, cfg: DySkewConfig) -> Dict:
+    """Shapes and dtypes of a (T, ...) stack of `types.link_state_init`
+    trees, one row per tenant slot: the same leaves by construction, so
+    a new metric leaf cannot silently desync the batched layout."""
     return jax.tree_util.tree_map(
-        lambda x: np.zeros((capacity,) + np.shape(x), x.dtype), template
+        lambda x: jax.ShapeDtypeStruct((capacity,) + x.shape, x.dtype),
+        jax.eval_shape(partial(link_state_init, n, cfg)),
     )
 
 
-def _batched_tick_impl(link, rows, sync, density, bpr, signal, active, *, cfg):
+def _zero_link_state(capacity: int, n: int, cfg: DySkewConfig) -> Dict:
+    """That stack's initial state, on the device.  Valid because every
+    leaf of the initial state is zero (LinkState.INIT == 0)."""
+    return jax.device_put(jax.tree_util.tree_map(
+        lambda x: np.zeros(x.shape, x.dtype),
+        _link_state_shapes(capacity, n, cfg),
+    ))
+
+
+#: Rows of the packed per-tick input: rows, sync, density, bytes per row,
+#: signal (0/1) and active (0/1, broadcast over the n instances).
+_PACKED_ROWS = 6
+
+
+def _batched_tick_impl(link, packed, *, cfg):
+    """Unpack the (T, 6, n) float32 tick input and advance every row."""
     return state_machine.tick_many(
         link,
         cfg,
-        rows_this_tick=rows,
-        sync_time_this_tick=sync,
-        batch_density=density,
-        bytes_per_row=bpr,
-        signal_this_tick=signal,
-        active=active,
+        rows_this_tick=packed[:, 0],
+        sync_time_this_tick=packed[:, 1],
+        batch_density=packed[:, 2],
+        bytes_per_row=packed[:, 3],
+        signal_this_tick=packed[:, 4] != 0,
+        active=packed[:, 5, 0] != 0,
     )
 
 
 class _JittedBatchedMachine:
-    """Caches one jitted `state_machine.tick_many` per (config, T, n)."""
+    """Caches, per (config, T, n), one jitted `state_machine.tick_many`
+    and that shape's all-zero initial state on the device.  Every driver
+    of the shape starts from the one state: JAX arrays are immutable and
+    the tick donates nothing, so sharing it is safe."""
 
-    _cache: Dict[Tuple, Callable] = {}
+    _cache: Dict[Tuple, Tuple[Callable, Dict]] = {}
 
     @classmethod
-    def get(cls, cfg: DySkewConfig, capacity: int, n: int) -> Callable:
+    def get(cls, cfg: DySkewConfig, capacity: int, n: int):
+        """(jitted tick, initial state) of the shape."""
         key = (cfg, capacity, n)
-        fn = cls._cache.get(key)
-        if fn is None:
-            fn = jax.jit(partial(_batched_tick_impl, cfg=cfg))
-            cls._cache[key] = fn
-        return fn
+        hit = cls._cache.get(key)
+        if hit is None:
+            hit = cls._cache[key] = (
+                jax.jit(partial(_batched_tick_impl, cfg=cfg)),
+                _zero_link_state(capacity, n, cfg),
+            )
+        return hit
 
 
 class BatchedLinkSim:
@@ -110,16 +132,9 @@ class BatchedLinkSim:
         self.num_tenants = num_tenants
         # Pad to a power of two so differently-sized suites share compiles.
         self.capacity = _next_pow2(num_tenants)
-        self.state = _stacked_host_link_state(self.capacity, n, cfg)
-        self._tick = _JittedBatchedMachine.get(cfg, self.capacity, n)
-
-    def _pad(self, x: np.ndarray, dtype) -> np.ndarray:
-        t = len(x)
-        if t == self.capacity:
-            return np.asarray(x, dtype)
-        out = np.zeros((self.capacity,) + np.shape(x)[1:], dtype)
-        out[:t] = x
-        return out
+        self._tick, self.state = _JittedBatchedMachine.get(
+            cfg, self.capacity, n
+        )
 
     def tick(
         self,
@@ -131,20 +146,22 @@ class BatchedLinkSim:
         active: np.ndarray,    # (T,) bool
     ) -> np.ndarray:
         """Advance the active tenants one tick; returns the (T, n) bool
-        distribute mask (False rows for inactive tenants)."""
+        distribute mask (False rows for inactive tenants).
+
+        The six inputs travel as ONE (capacity, 6, n) float32 array, the
+        jitted tick's only host argument; the float64→float32 casts
+        happen on assignment, as a cast would make them, and padded rows
+        stay zero (inactive).  The array is fresh each tick: a backend
+        may alias a host buffer it is given."""
         t = self.num_tenants
-        signal = np.asarray(signal, bool)
-        if signal.ndim == 1:
-            signal = np.broadcast_to(signal, (t, self.n))
-        self.state, distribute = self._tick(
-            self.state,
-            self._pad(rows, np.float32),
-            self._pad(sync, np.float32),
-            self._pad(density, np.float32),
-            self._pad(bpr, np.float32),
-            self._pad(signal, bool),
-            self._pad(active, bool),
-        )
+        packed = np.zeros((self.capacity, _PACKED_ROWS, self.n), np.float32)
+        packed[:t, 0] = rows
+        packed[:t, 1] = sync
+        packed[:t, 2] = density
+        packed[:t, 3] = bpr
+        packed[:t, 4] = np.asarray(signal, bool)
+        packed[:t, 5] = np.asarray(active, bool)[:, None]
+        self.state, distribute = self._tick(self.state, packed)
         with self.spans.span("dyskew.tick.wait"):
             return np.asarray(distribute)[:t]
 

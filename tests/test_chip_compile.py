@@ -25,7 +25,11 @@ from repro.kernels.dispatch.kernel import dispatch_gather
 from repro.kernels.histogram.kernel import load_histogram
 from repro.kernels.ssd_scan.kernel import ssd_state_scan
 from repro.kernels.topk_gating.kernel import topk_gating
-from repro.sim.batched_link import _batched_tick_impl, _stacked_host_link_state
+from repro.sim.batched_link import (
+    _PACKED_ROWS,
+    _batched_tick_impl,
+    _link_state_shapes,
+)
 
 
 @pytest.fixture(scope="module")
@@ -97,21 +101,20 @@ def test_kernel_compiles_for_v5e(name, one_chip):
 
 def test_batched_tick_compiles_for_v5e(one_chip):
     """`BatchedLinkSim`'s jitted tick at T=512 tenants x n=64 instances
-    (8 nodes x 8 interpreters), with the --many study's config."""
+    (8 nodes x 8 interpreters), with the --many study's config, fed its
+    one packed (T, 6, n) float32 input."""
     cfg = DySkewConfig(
         policy=Policy.LATE, skew_model=SkewModelKind.IDLE_TIME, n_strikes=2
     )
     T, n = 512, 64
     state = jax.tree_util.tree_map(
         lambda x: jax.ShapeDtypeStruct(x.shape, x.dtype, sharding=one_chip),
-        _stacked_host_link_state(T, n, cfg),
+        _link_state_shapes(T, n, cfg),
     )
-    f32 = jax.ShapeDtypeStruct((T, n), jnp.float32, sharding=one_chip)
-    mask = jax.ShapeDtypeStruct((T, n), jnp.bool_, sharding=one_chip)
-    active = jax.ShapeDtypeStruct((T,), jnp.bool_, sharding=one_chip)
+    packed = jax.ShapeDtypeStruct((T, _PACKED_ROWS, n), jnp.float32,
+                                  sharding=one_chip)
     compiled = _compile(
-        lambda *a: _batched_tick_impl(*a, cfg=cfg),
-        state, f32, f32, f32, f32, mask, active,
+        lambda *a: _batched_tick_impl(*a, cfg=cfg), state, packed
     )
     mem = compiled.memory_analysis()
     assert mem.argument_size_in_bytes > 0
